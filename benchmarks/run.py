@@ -98,6 +98,8 @@ def _parse_path(argv, flag):
 
 
 if __name__ == '__main__':
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(quick="--quick" in sys.argv[1:],
          json_path=_parse_path(sys.argv[1:], "--json"),
          metrics_path=_parse_path(sys.argv[1:], "--metrics"))

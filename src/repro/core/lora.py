@@ -6,6 +6,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from repro.core.psoft import host_svd
+
 
 def _kaiming(key, shape, dtype):
     fan_in = shape[0]
@@ -51,10 +53,10 @@ def pissa_init(w_pre, rank, param_dtype=jnp.bfloat16, peft_dtype=jnp.float32):
     TRAINABLE; the frozen base holds only the residual."""
     d_in, d_out = w_pre.shape
     r = min(rank, min(d_in, d_out))
-    u, s, vt = jnp.linalg.svd(w_pre.astype(jnp.float32), full_matrices=False)
-    sq = jnp.sqrt(s[:r])
-    a = u[:, :r] * sq[None, :]
-    b = sq[:, None] * vt[:r, :]
+    u, s, vt = host_svd(w_pre, r)
+    sq = jnp.sqrt(s)
+    a = u * sq[None, :]
+    b = sq[:, None] * vt
     return {
         "w": (w_pre.astype(jnp.float32) - a @ b).astype(param_dtype),
         "a": a.astype(peft_dtype),
@@ -104,11 +106,11 @@ def lora_xs_init(w_pre, rank, param_dtype=jnp.bfloat16, peft_dtype=jnp.float32):
     """Frozen SVD factors, trainable square core S (Bałazy et al., 2024)."""
     d_in, d_out = w_pre.shape
     r = min(rank, min(d_in, d_out))
-    u, s, vt = jnp.linalg.svd(w_pre.astype(jnp.float32), full_matrices=False)
+    u, s, vt = host_svd(w_pre, r)
     return {
         "w": w_pre.astype(param_dtype),
-        "a": u[:, :r].astype(param_dtype),                     # frozen
-        "b": (s[:r, None] * vt[:r, :]).astype(param_dtype),    # frozen
+        "a": u.astype(param_dtype),                            # frozen
+        "b": (s[:, None] * vt).astype(param_dtype),            # frozen
         "s": jnp.zeros((r, r), peft_dtype),                    # trainable core
     }
 

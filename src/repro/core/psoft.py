@@ -19,8 +19,40 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import scipy.linalg
 
 from repro.core import cayley
+
+
+def _svd_on_host(w: np.ndarray, rank: int):
+    # LAPACK first reduces a tall matrix by QR: factor the tall orientation
+    if w.shape[0] < w.shape[1]:
+        v, s, ut = _svd_on_host(w.T, rank)
+        return ut.T, s, v.T
+    u, s, vt = scipy.linalg.svd(np.asarray(w, np.float32),
+                                full_matrices=False, lapack_driver="gesdd",
+                                check_finite=False)
+    return u[:, :rank], s[:rank], vt[:rank]
+
+
+def host_svd(w: jax.Array, rank: int
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The top-``rank`` float32 singular triplets ``(u, s, vt)`` of a
+    (m, n) matrix, from a thin SVD by the host's LAPACK through a callback;
+    traceable (jit, vmap, eval_shape).
+
+    The one-time init SVD stays off the accelerator: a TPU compiles an SVD
+    at model widths in minutes and tens of GiB, while the host factors a
+    2048 x 8192 matrix in seconds.  ``w`` crosses in its own dtype and only
+    the rank-r factors come back."""
+    m, n = w.shape
+    r = min(rank, m, n)
+    shapes = tuple(jax.ShapeDtypeStruct(shape, jnp.float32)
+                   for shape in ((m, r), (r,), (r, n)))
+    return jax.pure_callback(
+        lambda a: tuple(x.astype(np.float32) for x in _svd_on_host(a, r)),
+        shapes, w, vmap_method="sequential")
 
 
 def psoft_init(w_pre: jax.Array, rank: int, relax_vectors: bool = True,
@@ -32,9 +64,8 @@ def psoft_init(w_pre: jax.Array, rank: int, relax_vectors: bool = True,
     """
     d_in, d_out = w_pre.shape
     r = min(rank, min(d_in, d_out))
-    u, s, vt = jnp.linalg.svd(w_pre.astype(jnp.float32), full_matrices=False)
-    a = u[:, :r]                                   # asymmetric split (Eq. 6)
-    b = s[:r, None] * vt[:r, :]
+    a, s, vt = host_svd(w_pre, r)                  # asymmetric split (Eq. 6)
+    b = s[:, None] * vt
     w_res = w_pre.astype(jnp.float32) - a @ b
     params = {
         "w_res": w_res.astype(param_dtype),

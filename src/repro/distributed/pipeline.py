@@ -22,17 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 PyTree = Any
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax.shard_map (new jax, check_vma kwarg)
-    falling back to jax.experimental.shard_map.shard_map (check_rep kwarg)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def gpipe_spmd_pipeline(body_fn: Callable, mesh: Mesh, axis: str = "stage"):
     """Build fn(stage_params, x_micro) running under shard_map.
 
@@ -91,10 +80,10 @@ def gpipe_spmd_pipeline(body_fn: Callable, mesh: Mesh, axis: str = "stage"):
     xspec = P()
 
     def wrapper(stage_params, x_micro):
-        fn = _shard_map(
-            per_device, mesh,
+        fn = jax.shard_map(
+            per_device, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: pspec, stage_params), xspec),
-            out_specs=xspec)
+            out_specs=xspec, check_vma=False)
         return fn(stage_params, x_micro)
 
     return wrapper

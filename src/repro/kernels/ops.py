@@ -138,9 +138,13 @@ def gather_delta_matmul(x: jax.Array, w: jax.Array, left: jax.Array,
     bn = 128
     while n % bn:
         bn //= 2
+    x = x.astype(compute_dtype)
+    u = jnp.einsum("bk,bkr->br", x,
+                   jnp.take(left, ids, axis=0).astype(compute_dtype),
+                   preferred_element_type=jnp.float32)
     return gather_delta_matmul_pallas(
-        ids, x.astype(compute_dtype), w.astype(compute_dtype),
-        left.astype(compute_dtype), right, bn=bn, interpret=interpret)
+        ids, x, w.astype(compute_dtype), u, right, bn=bn,
+        interpret=interpret)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,

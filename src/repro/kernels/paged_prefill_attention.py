@@ -40,7 +40,7 @@ NEG_INF = -1e30
 def _online_update(s, valid, v, acc_ref, m_ref, l_ref):
     """Fold one masked score block into the running softmax state.
 
-    s: (S, KH, G, T) raw scores; valid: broadcastable bool mask; v:
+    s: (KH, R, T) raw scores; valid: broadcastable bool mask; v:
     (T, KH, hd) values.  Explicit zeroing of fully-masked columns: a block
     with every position masked has s == m_new == NEG_INF and exp(s - m_new)
     would be 1, silently attending to garbage pages."""
@@ -51,12 +51,13 @@ def _online_update(s, valid, v, acc_ref, m_ref, l_ref):
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
     acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-        "skgt,tkd->skgd", p, v, preferred_element_type=jnp.float32)
+        "krt,tkd->krd", p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
 
 def _kernel(pt_ref, plen_ref, q_ref, ks_ref, vs_ref, kp_ref, vp_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, page_size: int, n_prefix_pages: int):
+            acc_ref, m_ref, l_ref, *, page_size: int, n_prefix_pages: int,
+            group: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -66,30 +67,34 @@ def _kernel(pt_ref, plen_ref, q_ref, ks_ref, vs_ref, kp_ref, vp_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    s_q, kh, g, hd = acc_ref.shape
-    q = q_ref[0].astype(jnp.float32).reshape(s_q, kh, g, hd)
+    hd = acc_ref.shape[-1]
+    # (KH, R, hd) with R = S * G: row r is query token r // G of the
+    # kv-head group — laid out by the wrapper so that no in-kernel reshape
+    # is needed (Mosaic refuses the head-splitting shape cast)
+    q = q_ref[0].astype(jnp.float32)
     scale = 1.0 / math.sqrt(hd)
 
     @pl.when(j < n_prefix_pages)
     def _prefix_page():
         k = kp_ref[0].astype(jnp.float32)            # (pg, kh, hd)
         v = vp_ref[0].astype(jnp.float32)
-        s = jnp.einsum("skgd,pkd->skgp", q, k,
+        s = jnp.einsum("krd,pkd->krp", q, k,
                        preferred_element_type=jnp.float32) * scale
-        pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         _online_update(s, pos < plen_ref[b], v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == n_prefix_pages)
     def _suffix():
         k = ks_ref[0].astype(jnp.float32)            # (S, kh, hd)
         v = vs_ref[0].astype(jnp.float32)
-        s = jnp.einsum("skgd,tkd->skgt", q, k,
+        s = jnp.einsum("krd,tkd->krt", q, k,
                        preferred_element_type=jnp.float32) * scale
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        ti = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
-        _online_update(s, qi >= ti, v, acc_ref, m_ref, l_ref)
+        ri = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ti = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        # causal: query r // G >= key t  <=>  r >= t * G
+        _online_update(s, ri >= ti * group, v, acc_ref, m_ref, l_ref)
         out = acc_ref[...] / jnp.maximum(l_ref[...][..., None], 1e-30)
-        o_ref[...] = out.reshape(1, s_q, kh * g, hd).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -105,13 +110,19 @@ def paged_prefill_attention_pallas(q, k, v, k_pool, v_pool, prefix_table,
     assert maxp >= 1, "pad an empty prefix table to one trash page"
     assert h % kh == 0, f"H={h} not divisible by KH={kh}"
     g = h // kh
+    rows = s * g
     pt_flat = prefix_table.reshape(-1).astype(jnp.int32)
+    # (B, S, KH, G, D) -> (B, KH, S*G, D): each kv head's query rows are
+    # one contiguous 2-D tile
+    qt = q.reshape(b, s, kh, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, kh, rows, hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, maxp + 1),
         in_specs=[
-            pl.BlockSpec((1, s, h, hd), lambda i, j, pt, ln: (i, 0, 0, 0)),
+            pl.BlockSpec((1, kh, rows, hd),
+                         lambda i, j, pt, ln: (i, 0, 0, 0)),
             pl.BlockSpec((1, s, kh, hd), lambda i, j, pt, ln: (i, 0, 0, 0)),
             pl.BlockSpec((1, s, kh, hd), lambda i, j, pt, ln: (i, 0, 0, 0)),
             # prefix pages stream by scalar-prefetched page id; the final
@@ -124,17 +135,20 @@ def paged_prefill_attention_pallas(q, k, v, k_pool, v_pool, prefix_table,
                          lambda i, j, pt, ln:
                          (pt[i * maxp + jnp.minimum(j, maxp - 1)], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, s, h, hd),
+        out_specs=pl.BlockSpec((1, kh, rows, hd),
                                lambda i, j, pt, ln: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((s, kh, g, hd), jnp.float32),   # output accumulator
-            pltpu.VMEM((s, kh, g), jnp.float32),       # running max
-            pltpu.VMEM((s, kh, g), jnp.float32),       # running sum
+            pltpu.VMEM((kh, rows, hd), jnp.float32),   # output accumulator
+            pltpu.VMEM((kh, rows), jnp.float32),       # running max
+            pltpu.VMEM((kh, rows), jnp.float32),       # running sum
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, page_size=pg, n_prefix_pages=maxp),
+    out = pl.pallas_call(
+        functools.partial(_kernel, page_size=pg, n_prefix_pages=maxp,
+                          group=g),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kh, rows, hd), q.dtype),
         interpret=interpret,
-    )(pt_flat, prefix_len.astype(jnp.int32), q, k, v, k_pool, v_pool)
+    )(pt_flat, prefix_len.astype(jnp.int32), qt, k, v, k_pool, v_pool)
+    return out.reshape(b, kh, s, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, s, h, hd)

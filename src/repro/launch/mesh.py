@@ -9,22 +9,18 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.configs.base import MeshConfig, ModelConfig
 from repro.sharding import ShardingRules, default_rules
 
 
-def _make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh, passing axis_types only where this jax supports it."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """jax.make_mesh with ``Auto`` axes: the sharding rules and the
+    trainer's ``with_sharding_constraint`` calls assume the compiler
+    propagates shardings (a bare ``jax.make_mesh`` gives ``Explicit``
+    axes, which refuse them).  ``devices`` defaults to all of them."""
+    return jax.make_mesh(shape, axes, devices=devices,
                          axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -33,11 +29,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: (2, 16, 16) = 512 chips ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_from_config(mc: MeshConfig) -> Mesh:
-    return _make_mesh(mc.shape, mc.axes)
+    return make_mesh(mc.shape, mc.axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -45,7 +41,7 @@ def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = jax.device_count()
     if data * model > n:
         data, model = n, 1
-    return _make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def rules_for(cfg: ModelConfig, mesh: Mesh,

@@ -342,6 +342,12 @@ class AdapterLifecycle:
         column's raw source."""
         eng = self.engine
         base = eng.adapters[self.base_name]
+        if [c.name for c in self.columns] == [self.base_name]:
+            # the serving base is the base column's own merge: every delta
+            # is zero, so no linear gets a bank — skip building them (a
+            # fine-tuned base would take the dense path, N·d_in·d_out f32
+            # per linear, only to find zeros)
+            return base
         entries = [eng._sources[c.name] for c in self.columns]
         pcs = [pc for _, pc in entries]
         names = [c.name for c in self.columns]

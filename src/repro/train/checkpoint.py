@@ -52,6 +52,11 @@ def save(tree: PyTree, ckpt_dir: str, step: int,
     os.makedirs(ckpt_dir, exist_ok=True)
     flat = _flatten(tree)   # device_get happens on the caller thread
     meta = {"step": int(step), **(extra_meta or {})}
+    # npz stores ml_dtypes arrays (bfloat16, ...) as raw bytes: keep their
+    # dtype names so restore can view the bytes as what they were
+    raw = {k: a.dtype.name for k, a in flat.items() if a.dtype.kind == "V"}
+    if raw:
+        meta["raw_dtypes"] = raw
 
     def _write():
         # unique tmp name: concurrent async+sync saves of the same step
@@ -126,6 +131,8 @@ def restore(template: PyTree, ckpt_dir: str, step: Optional[int] = None,
     path = os.path.join(ckpt_dir, f"step_{step:08d}", "arrays.npz")
     with np.load(path) as z:
         flat = {k: z[k] for k in z.files}
+    for k, name in load_meta(ckpt_dir, step).get("raw_dtypes", {}).items():
+        flat[k] = flat[k].view(jnp.dtype(name))
 
     leaves_kp, treedef = jax.tree_util.tree_flatten_with_path(template)
     shard_leaves = (jax.tree.leaves(shardings) if shardings is not None

@@ -66,3 +66,17 @@ def test_shape_mismatch_rejected(state, tmp_path):
 def test_missing_dir_raises(state, tmp_path):
     with pytest.raises(FileNotFoundError):
         checkpoint.restore(state, str(tmp_path / "nope"))
+
+
+def test_bfloat16_roundtrip(tmp_path):
+    """npz keeps bfloat16 (the frozen base's dtype) as raw bytes; restore
+    views them as bfloat16 again instead of failing to cast."""
+    tree = {"w": (jnp.arange(6, dtype=jnp.float32).reshape(2, 3) / 3
+                  ).astype(jnp.bfloat16),
+            "q": jnp.linspace(0.0, 1.0, 4, dtype=jnp.float32)}
+    checkpoint.save(tree, str(tmp_path), 1)
+    restored = checkpoint.restore(tree, str(tmp_path))
+    assert restored["w"].dtype == jnp.bfloat16
+    for k in tree:
+        np.testing.assert_array_equal(np.asarray(restored[k], np.float32),
+                                      np.asarray(tree[k], np.float32))

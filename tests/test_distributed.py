@@ -27,13 +27,13 @@ def test_sharded_train_step_runs():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, TrainConfig
-        from repro.launch.mesh import make_local_mesh, rules_for
+        from repro.launch.mesh import make_mesh, rules_for
         from repro.sharding import mesh_context
         from repro.train import trainer
         from repro.data import SyntheticLMDataset
         cfg = get_config('tiny')
         tc = TrainConfig(steps=3)
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         rules = rules_for(cfg, mesh, 'train')
         with mesh, mesh_context(mesh, rules):
             sh, _ = trainer.state_shardings(cfg, tc, mesh, rules)
@@ -58,19 +58,19 @@ def test_elastic_restore_across_meshes():
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, TrainConfig
         from repro.sharding import mesh_context
-        from repro.launch.mesh import rules_for
+        from repro.launch.mesh import make_mesh, rules_for
         from repro.train import trainer, checkpoint
         cfg = get_config('tiny'); tc = TrainConfig(steps=2)
         key = jax.random.PRNGKey(0)
         d = tempfile.mkdtemp()
-        mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh_a = make_mesh((4, 2), ('data', 'model'))
         rules = rules_for(cfg, mesh_a, 'train')
         with mesh_a, mesh_context(mesh_a, rules):
             sh_a, _ = trainer.state_shardings(cfg, tc, mesh_a, rules)
             state = jax.device_put(trainer.init_train_state(key, cfg, tc),
                                    sh_a)
             checkpoint.save(state, d, 1)
-        mesh_b = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh_b = make_mesh((2, 4), ('data', 'model'))
         rules_b = rules_for(cfg, mesh_b, 'train')
         with mesh_b, mesh_context(mesh_b, rules_b):
             sh_b, _ = trainer.state_shardings(cfg, tc, mesh_b, rules_b)
@@ -85,7 +85,8 @@ def test_gpipe_pipeline_forward_and_grad():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed import gpipe_spmd_pipeline
-        mesh = jax.make_mesh((8,), ('stage',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ('stage',))
         S, n_micro, mb, d = 8, 16, 2, 32
         ws = jax.random.normal(jax.random.PRNGKey(1), (S, d, d)) * 0.1
         x = jax.random.normal(jax.random.PRNGKey(2), (n_micro, mb, d))
@@ -113,12 +114,12 @@ def test_grad_allreduce_dtype_in_hlo():
     print(run_sub("""
         import jax, jax.numpy as jnp, re
         from repro.configs import get_config, TrainConfig
-        from repro.launch.mesh import rules_for
+        from repro.launch.mesh import make_mesh, rules_for
         from repro.sharding import mesh_context
         from repro.train import trainer
         from repro.data import make_input_specs
         cfg = get_config('tiny')
-        mesh = jax.make_mesh((8, 1), ('data', 'model'))
+        mesh = make_mesh((8, 1), ('data', 'model'))
         for dtype, expect in (('', False), ('bfloat16', True)):
             tc = TrainConfig(steps=2, grad_allreduce_dtype=dtype,
                              full_finetune=True)

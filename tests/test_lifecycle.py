@@ -390,6 +390,29 @@ def _lora_adapter(seed, rank):
     return w, out, cfg
 
 
+def test_finetuned_base_serves_its_merge_without_banks(setup):
+    """An engine built on a fine-tuned PSOFT tree serves that tree's merge
+    as its base: no linear carries a bank (the dense-delta fallback is
+    never built) and tokens match an engine on the merged weights."""
+    cfg, params = setup
+    tuned = nudge_psoft(params, 0.05)
+    eng = ServeEngine(tuned, cfg, max_len=48, slots=2)
+    tree = eng._banked_tree()
+    assert tree is eng.adapters["base"]
+    assert not any("bank" in jax.tree_util.keystr(kp) for kp, _ in
+                   jax.tree_util.tree_leaves_with_path(tree))
+    plain = ServeEngine(eng.adapters["base"], cfg.replace(
+        peft=PEFTConfig(method="none", target_modules=())),
+        max_len=48, slots=2)
+
+    def reqs():
+        return [Request(uid=i, prompt=_prompt(6, 30 * i, cfg),
+                        max_new_tokens=6) for i in range(2)]
+    got = {r.uid: r.generated for r in eng.run(reqs(), max_steps=64)}
+    want = {r.uid: r.generated for r in plain.run(reqs(), max_steps=64)}
+    assert got == want
+
+
 def test_extend_bank_matches_full_stack():
     """Growing a bank one adapter at a time is bitwise identical to
     stacking all adapters at once — including rank growth (zero-padding

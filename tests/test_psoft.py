@@ -94,6 +94,29 @@ def test_svd_reconstruction():
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("shape", [(3, 40, 24), (3, 24, 40)],
+                         ids=["tall", "wide"])
+def test_host_svd_traces_like_jnp_svd(shape):
+    """The host-LAPACK SVD behind the init traces under jit, vmap and
+    eval_shape, takes a bf16 matrix as it is, and gives the top-r
+    triplets of ``jnp.linalg.svd``."""
+    r = 5
+    w = jax.random.normal(jax.random.PRNGKey(5), shape).astype(jnp.bfloat16)
+    fn = jax.jit(jax.vmap(lambda x: psoft.host_svd(x, r)))
+    assert [s.shape for s in jax.eval_shape(fn, w)] == \
+        [(3, shape[1], r), (3, r), (3, r, shape[2])]
+    u, s, vt = fn(w)
+    u0, s0, vt0 = jnp.linalg.svd(w.astype(jnp.float32), full_matrices=False)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s0[:, :r]),
+                               rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray((u * s[:, None, :]) @ vt),
+        np.asarray((u0[..., :r] * s0[:, None, :r]) @ vt0[:, :r]), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(jnp.swapaxes(u, 1, 2) @ u),
+                               np.broadcast_to(np.eye(r), (3, r, r)),
+                               atol=1e-5)
+
+
 @hypothesis.given(st.integers(2, 300))
 @hypothesis.settings(max_examples=20, deadline=None)
 def test_param_count_formula(r):

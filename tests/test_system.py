@@ -116,3 +116,49 @@ def test_train_driver_end_to_end(tmp_path):
                             "8", "--seq", "32", "--ckpt", ck,
                             "--ckpt-every", "8", "--log-every", "4"])
     assert np.isfinite(loss2)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache sits
+    at one fixed directory of the checkout.  Nothing compiles while the
+    setting is changed, and the previous one is restored."""
+    import os
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            root = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            want = os.path.join(root, ".jax_cache")
+            assert compile_cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_train_driver_saves_each_step_once(tmp_path, monkeypatch):
+    """A step that is both a periodic and the final checkpoint is saved
+    once: an async and a sync save of one step race on the rename, and
+    a restore right after the run can find no checkpoint."""
+    from repro.launch import train as train_mod
+    from repro.train import checkpoint
+    saved = []
+    real_save = checkpoint.save
+
+    def save(tree, ckpt_dir, step, **kw):
+        saved.append(step)
+        return real_save(tree, ckpt_dir, step, **kw)
+
+    monkeypatch.setattr(checkpoint, "save", save)
+    ck = str(tmp_path / "ck")
+    train_mod.main(["--arch", "tiny", "--steps", "4", "--batch", "8",
+                    "--seq", "32", "--ckpt", ck, "--ckpt-every", "2",
+                    "--log-every", "4"])
+    assert saved == [2, 4]
+    assert checkpoint.latest_step(ck) == 4
