@@ -110,6 +110,9 @@ class _CountingNoopTracker(NoopTracker):
     def event(self, *a, **k):
         self.calls += 1
 
+    def span(self, *a, **k):
+        self.calls += 1
+
     def time_block(self, *a, **k):
         self.calls += 1
         return super().time_block(*a, **k)

@@ -69,9 +69,9 @@ def default_config(root: str) -> AnalysisConfig:
     """The repo's own invariant map.
 
     Hot-path sets mirror the runtime pins they replace: the OBS-GATE list
-    is exactly the per-decode-step call graph that ``bench_serve``'s
-    NoopTracker counter guards (admission/prefill span timers run once per
-    request and stay caller-discretion); the HOSTSYNC allowlist is the
+    is the engine step's call graph — decode, admission, chunk
+    continuation, finish — that ``bench_serve``'s NoopTracker counter and
+    ``tests/test_obs.py`` guard; the HOSTSYNC allowlist is the
     engine's one sanctioned host boundary — the post-sample token
     materialization, which PR 9 consolidated into single ``jax.device_get``
     batched transfers."""
@@ -98,10 +98,14 @@ def default_config(root: str) -> AnalysisConfig:
             ("src/repro/train/trainer.py", "state_shardings"),
         ),
         obsgate_hot={
-            _ENGINE: ("*.run_stream", "*._decode_live", "*._spec_step",
-                      "*._spec_group", "*._sample_rows",
+            _ENGINE: ("*.run_stream", "*._decode_step", "*._decode_live",
+                      "*._spec_step", "*._spec_group", "*._sample_rows",
                       "*._ensure_decode_pages", "*._suspend",
-                      "*._finish_slot"),
+                      "*._finish_slot", "*._admit*", "*._try_admit_pages",
+                      "*._run_prefill_groups", "*._continue_prefills",
+                      "*._record_admissions", "*._finish_chunks",
+                      "*._finish_admitted", "*._span", "*._stamp_*",
+                      "*._observe_compiles"),
             "src/repro/serve/scheduler.py": ("*.push", "*.window"),
         },
     )

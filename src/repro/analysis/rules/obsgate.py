@@ -2,10 +2,10 @@
 
 ``bench_serve`` pins the decode loop to ZERO tracker calls per step under
 ``NoopTracker`` (the <2% overhead guard).  That runtime counter becomes a
-static rule here: inside the configured hot functions (the per-decode-step
-call graph: ``run_stream``'s loop body, ``_decode_live``, ``_spec_step``,
-``_spec_group``, ``_sample_rows``, suspension/finish paths), every tracker
-method call must sit under an ``if self._obs:`` / ``if not
+static rule here: inside the configured hot functions (the engine step's
+call graph: ``run_stream``'s loop body, decode, sampling, admission,
+chunk continuation, suspension/finish paths), every tracker
+method call (spans included) must sit under an ``if self._obs:`` / ``if not
 tracker.is_noop:`` guard — as an enclosing ``if``, a ternary
 (``tracker.time_block(...) if self._obs else NULL_SPAN``), or a
 function-level early return (``if not self._obs: return``).
@@ -22,7 +22,7 @@ from typing import Iterator
 from ..core import FileContext, Finding, match_any, rule
 
 _TRACKER_METHODS = {"count", "gauge", "histogram", "event", "log",
-                    "time_block"}
+                    "time_block", "span"}
 
 
 def _is_tracker_call(node: ast.AST) -> bool:

@@ -1,3 +1,3 @@
 from repro.obs.tracker import (  # noqa: F401
     NOOP, NULL_SPAN, SCHEMA_VERSION, CompositeTracker, InMemoryTracker,
-    JsonlTracker, NoopTracker, Tracker, read_jsonl, replay)
+    JsonlTracker, NoopTracker, Tracker, clock_ns, read_jsonl, replay)

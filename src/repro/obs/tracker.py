@@ -3,19 +3,21 @@
 One small levanter-style surface — ``log`` scalars/dicts against a
 monotonically increasing step, ``count``/``gauge``/``histogram``
 primitives, structured ``event`` records, and a ``time_block(name)``
-context manager for wall-clock spans — with four backends:
+context manager for spans — with four backends:
 
 * :class:`NoopTracker` — the default everywhere.  Every method is a bare
   ``pass`` and ``time_block`` returns a shared null context manager, so an
   uninstrumented-by-choice hot loop pays one attribute lookup + call per
-  record site and **never** touches ``perf_counter`` (the span is never
-  measured).  The serving engine additionally gates its per-step
-  aggregation behind an ``is_noop`` check, so the default decode path does
-  no metric bookkeeping at all (guarded in ``benchmarks/bench_serve.py``).
+  record site and **never** reads the clock or opens a profiler
+  annotation (the span is never measured).  The serving engine
+  additionally gates its per-step aggregation behind an ``is_noop``
+  check, so the default decode path does no metric bookkeeping at all
+  (guarded in ``benchmarks/bench_serve.py``).
 * :class:`InMemoryTracker` — accumulates counters / last-value gauges /
-  histogram observations / events in host dicts; the capture backend for
-  tests, examples, and benchmark summaries (:meth:`InMemoryTracker.quantile`
-  matches ``numpy.quantile`` exactly — pinned in ``tests/test_obs.py``).
+  histogram observations / events / spans in host dicts; the capture
+  backend for tests, examples, and benchmark summaries
+  (:meth:`InMemoryTracker.quantile` matches ``numpy.quantile`` exactly —
+  pinned in ``tests/test_obs.py``).
 * :class:`JsonlTracker` — append-only line-delimited JSON with a stable
   schema (see :data:`SCHEMA_VERSION` and :func:`read_jsonl`); the artifact
   backend CI uploads.
@@ -27,7 +29,18 @@ increments, totals only grow.  Gauges are last-write-wins point-in-time
 values.  Histograms record raw observations (no binning — backends keep
 the values, quantiles are computed exactly on read).  Events are named
 dict payloads for structured occurrences (admissions, preemptions,
-bench rows) that don't reduce to one scalar.
+bench rows) that don't reduce to one scalar.  Spans are intervals with a
+start, an end, a parent (the span of the same tracker open around them)
+and an optional request ``uid``; a backend files each span's duration as
+a histogram observation under the span's name as well.
+
+**Clock.**  Span ends are ``time.time_ns()`` — the clock of the JAX
+profiler's trace — and ``time_block`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profiler
+trace is recording, every span appears in its host plane beside the
+device's ops (one constant offset apart: the trace counts from the
+session's start).  :func:`clock_ns` maps a ``time.perf_counter()`` stamp
+onto that clock.
 
 **Steps.**  Every record carries an optional ``step``.  Steps must be
 monotonically non-decreasing per tracker (a regression raises — mixing
@@ -75,12 +88,20 @@ _NULL_SPAN = _NullSpan()
 NULL_SPAN = _NULL_SPAN
 
 
+def clock_ns(perf_s: float) -> int:
+    """The span clock (``time.time_ns``) at the ``time.perf_counter()``
+    stamp ``perf_s``."""
+    return time.time_ns() - round((time.perf_counter() - perf_s) * 1e9)
+
+
 class _Span:
-    """Wall-clock span: measures ``perf_counter`` across the ``with`` body
-    and records the elapsed seconds as a histogram observation under
-    ``name``.  Spans measure *host* wall-clock — for async jax dispatch
-    that is dispatch time unless the caller blocks inside the span."""
-    __slots__ = ("_tracker", "_name", "_step", "_t0", "seconds")
+    """One ``time_block``: records the interval of the ``with`` body as a
+    span record under ``name``, parented to the tracker's innermost open
+    span, and marks the same interval in the profiler's host plane.  Spans
+    measure *host* wall-clock — for async jax dispatch that is dispatch
+    time unless the caller blocks inside the span."""
+    __slots__ = ("_tracker", "_name", "_step", "_parent", "_ann",
+                 "start_ns", "end_ns", "seconds")
 
     def __init__(self, tracker: "Tracker", name: str, step: Optional[int]):
         self._tracker = tracker
@@ -89,12 +110,22 @@ class _Span:
         self.seconds: Optional[float] = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        from jax.profiler import TraceAnnotation   # jax only when recording
+        stack = self._tracker._open
+        self._parent = stack[-1] if stack else None
+        stack.append(self._name)
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self.start_ns = time.time_ns()
         return self
 
     def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self._t0
-        self._tracker.histogram(self._name, self.seconds, step=self._step)
+        self.end_ns = time.time_ns()
+        self._ann.__exit__(*exc)
+        self._tracker._open.pop()
+        self.seconds = (self.end_ns - self.start_ns) / 1e9
+        self._tracker.span(self._name, self.start_ns, self.end_ns,
+                           step=self._step, parent=self._parent)
         return False
 
 
@@ -111,6 +142,8 @@ class Tracker:
 
     def __init__(self) -> None:
         self._last_step = 0
+        #: names of the time_block spans open now, innermost last
+        self._open: List[str] = []
 
     # -- step domain -------------------------------------------------------
     def _step_of(self, step: Optional[int]) -> int:
@@ -163,9 +196,24 @@ class Tracker:
         self._record("event", name, None, dict(data), self._step_of(step))
 
     def time_block(self, name: str, *, step: Optional[int] = None):
-        """Context manager measuring the wall-clock seconds of its body as
-        a histogram observation under ``name``."""
+        """Context manager recording its body's interval as a span under
+        ``name`` (duration also filed as a histogram observation)."""
         return _Span(self, name, step)
+
+    def span(self, name: str, start_ns: int, end_ns: int, *,
+             uid: Any = None, step: Optional[int] = None,
+             parent: Optional[str] = None) -> None:
+        """Record the interval ``[start_ns, end_ns]`` on the span clock (see
+        :func:`clock_ns`) — for intervals no ``with`` block can enclose,
+        such as a request's time in the queue.  ``uid`` names the request
+        the span belongs to; ``parent`` the enclosing span."""
+        start_ns, end_ns = int(start_ns), int(end_ns)
+        if end_ns < start_ns:
+            raise ValueError(f"span {name!r} ends before it starts "
+                             f"({end_ns} < {start_ns})")
+        self._record("span", name, (end_ns - start_ns) / 1e9,
+                     {"start_ns": start_ns, "end_ns": end_ns,
+                      "parent": parent, "uid": uid}, self._step_of(step))
 
     def finish(self) -> None:
         """Flush/close the backend (idempotent; no-op by default)."""
@@ -179,7 +227,8 @@ class Tracker:
 
 class NoopTracker(Tracker):
     """Discards everything.  The default tracker of every instrumented
-    layer: record sites cost one call, spans never read the clock."""
+    layer: record sites cost one call, spans never read the clock and open
+    no profiler annotation."""
 
     is_noop = True
 
@@ -201,6 +250,10 @@ class NoopTracker(Tracker):
     def time_block(self, name, *, step=None):
         return _NULL_SPAN
 
+    def span(self, name, start_ns, end_ns, *, uid=None, step=None,
+             parent=None):
+        pass
+
     def _record(self, kind, name, value, data, step):  # pragma: no cover
         pass
 
@@ -214,7 +267,10 @@ class InMemoryTracker(Tracker):
     """Accumulating host-side backend (tests / examples / summaries).
 
     ``counters``: name -> running total.  ``gauges``: name -> last value.
-    ``histograms``: name -> list of raw observations.  ``events``: list of
+    ``histograms``: name -> list of raw observations (span durations
+    included, in seconds).  ``spans``: list of ``{"step", "name",
+    "start_ns", "end_ns", "parent", "uid"}`` dicts in record (end) order.
+    ``events``: list of
     ``{"step", "name", **payload}`` dicts in record order — payload keys
     shadow the record's ``step``/``name`` (the engine uses this to keep
     per-run steps on admission events), so don't put a ``name`` in a
@@ -229,6 +285,7 @@ class InMemoryTracker(Tracker):
         self.histograms: Dict[str, List[float]] = {}
         self.events: List[Dict[str, Any]] = []
         self.scalars: Dict[str, List] = {}
+        self.spans: List[Dict[str, Any]] = []
 
     def _record(self, kind, name, value, data, step):
         if kind == "count":
@@ -242,6 +299,9 @@ class InMemoryTracker(Tracker):
                 self.scalars.setdefault(k, []).append((step, v))
         elif kind == "event":
             self.events.append({"step": step, "name": name, **data})
+        elif kind == "span":
+            self.histograms.setdefault(name, []).append(value)
+            self.spans.append({"step": step, "name": name, **data})
 
     # -- read side ---------------------------------------------------------
     def counter(self, name: str) -> float:
@@ -261,6 +321,9 @@ class InMemoryTracker(Tracker):
     def events_named(self, name: str) -> List[Dict[str, Any]]:
         return [e for e in self.events if e["name"] == name]
 
+    def spans_named(self, name: str) -> List[Dict[str, Any]]:
+        return [s for s in self.spans if s["name"] == name]
+
     def counters_under(self, prefix: str) -> Dict[str, float]:
         """Counters whose name starts with ``prefix`` (e.g. per-adapter
         token totals under ``"engine/tokens/"``), prefix stripped."""
@@ -279,9 +342,16 @@ class JsonlTracker(Tracker):
          "data": {"train/loss": 2.1}}
         {"v": 1, "t": ..., "step": ..., "kind": "event",
          "name": "engine/admission", "data": {...}}
+        {"v": 1, "t": ..., "step": ..., "kind": "span",
+         "name": "engine/step", "value": <seconds>,
+         "data": {"start_ns": ..., "end_ns": ..., "parent": null,
+                  "uid": null}}
 
     ``count``/``gauge``/``histogram`` carry ``name`` + ``value``;
-    ``scalars`` carries ``data``; ``event`` carries ``name`` + ``data``.
+    ``scalars`` carries ``data``; ``event`` carries ``name`` + ``data``;
+    ``span`` carries ``name``, its duration as ``value``, and ``data`` with
+    integer ``start_ns`` <= ``end_ns``, ``parent`` (a span name or null)
+    and ``uid`` (a request id or null).
     Lines are written eagerly (line-buffered semantics) so a crashed run
     still leaves a readable prefix; :func:`read_jsonl` is the validated
     read side.
@@ -355,8 +425,24 @@ def read_jsonl(path: str) -> List[Dict[str, Any]]:
                         or not isinstance(rec.get("data"), dict):
                     raise ValueError(
                         f"{path}:{i}: event record needs name + data")
+            elif kind == "span" and not _valid_span(rec):
+                raise ValueError(
+                    f"{path}:{i}: span record needs name + value + data "
+                    f"with integer start_ns <= end_ns, parent and uid")
             out.append(rec)
     return out
+
+
+def _valid_span(rec: Mapping[str, Any]) -> bool:
+    data = rec.get("data")
+    if not (isinstance(rec.get("name"), str)
+            and isinstance(rec.get("value"), (int, float))
+            and isinstance(data, dict)):
+        return False
+    a, b = data.get("start_ns"), data.get("end_ns")
+    parent = data.get("parent", False)
+    return (isinstance(a, int) and isinstance(b, int) and a <= b
+            and "uid" in data and (parent is None or isinstance(parent, str)))
 
 
 def replay(records: Sequence[Mapping[str, Any]],
@@ -377,6 +463,10 @@ def replay(records: Sequence[Mapping[str, Any]],
             t.log(rec["data"], step=rec["step"])
         elif kind == "event":
             t.event(rec["name"], rec["data"], step=rec["step"])
+        elif kind == "span":
+            d = rec["data"]
+            t.span(rec["name"], d["start_ns"], d["end_ns"], uid=d["uid"],
+                   step=rec["step"], parent=d["parent"])
     return t
 
 
@@ -413,6 +503,12 @@ class CompositeTracker(Tracker):
         if self.is_noop:
             return _NULL_SPAN
         return _Span(self, name, step)
+
+    def span(self, name, start_ns, end_ns, *, uid=None, step=None,
+             parent=None):
+        for c in self.children:
+            c.span(name, start_ns, end_ns, uid=uid, step=step,
+                   parent=parent)
 
     def finish(self):
         for c in self.children:

@@ -70,6 +70,7 @@ costs a gather.
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -80,7 +81,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, PEFTConfig
 from repro.core import peft as peft_lib, registry as peft_registry
 from repro.models import model as model_lib
-from repro.obs import NOOP, NULL_SPAN, Tracker
+from repro.obs import NOOP, NULL_SPAN, Tracker, clock_ns
 from repro.serve import sampling as sampling_lib
 from repro.serve.kv_cache import OutOfPages, PagedKVCache, TRASH_PAGE
 from repro.serve.lifecycle import AdapterLifecycle
@@ -175,6 +176,12 @@ class Request:
     #: wall-clock analogues of ``arrival_step`` / ``finish_step``
     arrival_cost: float = 0.0
     finish_cost: Optional[float] = None
+    #: ``time.perf_counter()`` stamps (engine-set): when the request was
+    #: submitted, first taken off the queue into a slot, and when its first
+    #: token reached the host — TTFT's queueing and prefill parts
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first_token: Optional[float] = None
 
     def __post_init__(self):
         if self.deadline_steps is not None:
@@ -505,6 +512,8 @@ class ServeEngine:
         #: cumulative engine steps ever served — the tracker's step domain
         #: (``self._step`` resets per run; tracker steps must be monotone)
         self._obs_step = 0
+        #: (decode, prefill) trace counts last read into engine/compiles
+        self._seen_traces = (0, 0)
         self._tracker = NOOP
         self._obs = False
         if tracker is not None:
@@ -541,9 +550,60 @@ class ServeEngine:
         # (pinned by the trace-count test in tests/test_obs.py)
         self._tracker = t
         self._obs = not t.is_noop
+        self._seen_traces = (self._decode_traces, self._prefill_traces)
         self.scheduler.tracker = t
         if self.kv is not None:
             self.kv.set_tracker(t)
+
+    def _span(self, name: str):
+        """A span of the engine's step under ``name``; the shared null span
+        (no tracker call, no clock read) when nothing records."""
+        return (self._tracker.time_block(name, step=self._obs_step)
+                if self._obs else NULL_SPAN)
+
+    def _request_span(self, name: str, r: "Request",
+                      t0: Optional[float], t1: float) -> None:
+        """Record ``r``'s interval between two of its perf_counter stamps
+        (the caller gates on ``_obs``)."""
+        if t0 is None:
+            return
+        end = clock_ns(t1)
+        self._tracker.span(name, end - round((t1 - t0) * 1e9), end,
+                           uid=r.uid, step=self._obs_step)
+
+    def _stamp_admit(self, r: "Request") -> None:
+        """Stamp ``r``'s first admission (leaving the queue for a slot)."""
+        if r.t_admit is not None:
+            return
+        r.t_admit = time.perf_counter()
+        if self._obs:
+            self._request_span("request/queued", r, r.t_submit, r.t_admit)
+
+    def _stamp_first_token(self, r: "Request") -> None:
+        """Stamp the arrival of ``r``'s first token on the host."""
+        if r.t_first_token is not None:
+            return
+        r.t_first_token = time.perf_counter()
+        if self._obs:
+            self._request_span("request/prefill", r, r.t_admit,
+                               r.t_first_token)
+
+    def _observe_compiles(self, step: int) -> None:
+        """Count the programs compiled since the last read
+        (``engine/compiles``) and name each (``engine/compile``)."""
+        if not self._obs:
+            return
+        seen = (self._decode_traces, self._prefill_traces)
+        for program, now, before in zip(("decode", "prefill"), seen,
+                                        self._seen_traces):
+            if now > before:
+                self._tracker.count("engine/compiles", now - before,
+                                    step=self._obs_step)
+                for _ in range(now - before):
+                    self._tracker.event("engine/compile",
+                                        {"step": step, "program": program},
+                                        step=self._obs_step)
+        self._seen_traces = seen
 
     @property
     def admission_log(self) -> List[Tuple[int, int, int, List[int]]]:
@@ -848,8 +908,9 @@ class ServeEngine:
         # ONE batched device->host transfer for the step's sample outputs
         # (None logprob leaves pass through untouched) instead of a
         # blocking np.asarray round-trip per array
-        toks, chosen, top_ids, top_lps = jax.device_get(
-            (toks, chosen, top_ids, top_lps))
+        with self._span("engine/device_wait"):
+            toks, chosen, top_ids, top_lps = jax.device_get(
+                (toks, chosen, top_ids, top_lps))
         if want_lp:
             for j, r in enumerate(reqs):
                 n = 0 if r is None else self._sampling_for(r).logprobs
@@ -928,6 +989,7 @@ class ServeEngine:
             tok = next_tokens[j]
             if tok is not None:
                 r.generated.append(int(tok))
+                self._stamp_first_token(r)
             if final:
                 r._prefill_done = True
                 self.positions[slot] = len(seq)
@@ -954,9 +1016,6 @@ class ServeEngine:
                                  step=s)
                 if self.scheduler.at_risk(r, self._cost_clock):
                     tr.count("scheduler/at_risk_admissions", step=s)
-                if final and self.prefill_chunk_tokens is not None:
-                    tr.histogram("engine/prefill_stall_steps",
-                                 step - r.admit_step, step=s)
 
     def _admit(self, step: int):
         """Fill every free slot from the scheduler.
@@ -980,11 +1039,12 @@ class ServeEngine:
             # step budget spent and other work is progressing: defer new
             # admissions (an idle engine always admits — no starvation)
             return
-        tree = self._banked_tree()
-        if self.cache_mode == "paged":
-            self._admit_paged(tree, free, step)
-        else:
-            self._admit_dense(tree, free, step)
+        with self._span("engine/admit"):
+            tree = self._banked_tree()
+            if self.cache_mode == "paged":
+                self._admit_paged(tree, free, step)
+            else:
+                self._admit_dense(tree, free, step)
 
     def _admit_dense(self, tree, free, step: int):
         # dense slots always fit: admit straight down the policy order
@@ -994,6 +1054,7 @@ class ServeEngine:
         while free and self.scheduler.has_work():
             r, _resumed = self.scheduler.window(self._cost_clock)[0]
             self.scheduler.remove(r)
+            self._stamp_admit(r)
             self._pin(r)
             seq = np.asarray(r.prompt, np.int32)
             admitted.append((free.pop(0), r, 0, seq, False, len(seq), True))
@@ -1010,13 +1071,13 @@ class ServeEngine:
                 lens[j] = len(seq)
                 ids[j] = self._slot_col(r)
             self._step_spent += self.cost_model.prefill_cost(int(lens.sum()))
-            with self._tracker.time_block("engine/prefill_s",
-                                          step=self._obs_step):
+            with self._span("engine/prefill_s"):
                 logits, cache = self._prefill(
                     tree, {"tokens": jnp.asarray(toks)}, jnp.asarray(lens),
                     jnp.asarray(ids))
-            nxt = self._sample_rows(logits[:, -1, :self.cfg.vocab_size],
-                                    [e[1] for e in group])
+            with self._span("engine/sample"):
+                nxt = self._sample_rows(logits[:, -1, :self.cfg.vocab_size],
+                                        [e[1] for e in group])
             for j, (slot, r, _pref, _seq, _res, _end, _fin) in \
                     enumerate(group):
                 self._install_cache(slot, cache, j)
@@ -1131,7 +1192,9 @@ class ServeEngine:
             pick = None
             skipped = 0
             for r, resumed in self.scheduler.window(self._cost_clock):
-                res = self._try_admit_pages(free, r, resumed, step, frozen)
+                with self._span("kv/admit_pages"):
+                    res = self._try_admit_pages(free, r, resumed, step,
+                                                frozen)
                 if res is not None:
                     pick = (r, resumed) + res
                     break
@@ -1143,6 +1206,7 @@ class ServeEngine:
                 break          # retry after running slots free pages
             r, resumed, prefix, seq = pick
             self.scheduler.remove(r)
+            self._stamp_admit(r)
             self._pin(r)       # no-op for resumed: they keep their epoch
             slot = free.pop(0)
             frozen.add(slot)
@@ -1205,8 +1269,7 @@ class ServeEngine:
             # the kernel/reference mask both handle that exactly)
             n_pref = kv.pages_per_slot if prefs.max() else 0
             self._step_spent += self.cost_model.prefill_cost(int(lens.sum()))
-            with self._tracker.time_block("engine/prefill_s",
-                                          step=self._obs_step):
+            with self._span("engine/prefill_s"):
                 logits, new_pools = self._prefill_paged(
                     tree, {"tokens": jnp.asarray(toks)}, kv.pools,
                     jnp.asarray(rows_pt), jnp.asarray(rows_pt[:, :n_pref]),
@@ -1219,17 +1282,15 @@ class ServeEngine:
             sample_for = [r if (fin and not r.generated) else None
                           for _s, r, _p, _sq, _res, _e, fin in group]
             if any(q is not None for q in sample_for):
-                toks_out = self._sample_rows(
-                    logits[:, -1, :self.cfg.vocab_size], sample_for)
+                with self._span("engine/sample"):
+                    toks_out = self._sample_rows(
+                        logits[:, -1, :self.cfg.vocab_size], sample_for)
                 nxt = [None if sample_for[j] is None else int(toks_out[j])
                        for j in range(g)]
             else:
                 nxt = [None] * g
             for slot, r, _pref, seq, _res, end, _fin in group:
                 kv.commit_prompt(slot, seq[:end], self._kv_key(r))
-            if self._obs and self.prefill_chunk_tokens is not None:
-                self._tracker.count("engine/prefill_chunks", g,
-                                    step=self._obs_step)
             out.append((group, nxt))
         return out
 
@@ -1294,20 +1355,16 @@ class ServeEngine:
             tok = next_tokens[j]
             if tok is not None:
                 r.generated.append(int(tok))
+                self._stamp_first_token(r)
             if final:
                 r._prefill_done = True
                 self.positions[slot] = len(seq)
             else:
                 r._prefill_pos = end
                 self.positions[slot] = 0
-            if self._obs:
-                tr = self._tracker
-                s = self._obs_step
-                if tok is not None:
-                    tr.count(f"engine/tokens/{r.adapter}", step=s)
-                if final:
-                    tr.histogram("engine/prefill_stall_steps",
-                                 step - r.admit_step, step=s)
+            if self._obs and tok is not None:
+                self._tracker.count(f"engine/tokens/{r.adapter}",
+                                    step=self._obs_step)
 
     def _install_cache(self, slot: int, cache, j: int):
         """Dense mode only: copy prefill row ``j`` into slot ``slot`` of the
@@ -1349,58 +1406,66 @@ class ServeEngine:
     def _decode_live(self, tree, live: List[int], step: int):
         """One decode step over every live slot; returns (last-pos logits,
         surviving live slots — pool pressure may suspend some)."""
-        if self.cache_mode == "paged":
-            live = self._ensure_decode_pages(live, step)
-            if not live:
-                return None, live
-        toks = np.zeros((self.slots, 1), np.int32)
-        ids = np.zeros((self.slots,), np.int32)
-        positions = np.zeros((self.slots,), np.int32)
-        for i in live:
-            toks[i, 0] = self.active[i].generated[-1]
-            ids[i] = self._slot_col(self.active[i])
-            positions[i] = self.positions[i]
-        # dead rows decode as ghosts (token 0, adapter 0): their positions
-        # are pinned to 0 above, and in paged mode their table rows must be
-        # all-trash — so a future table bug corrupts loudly here instead of
-        # silently absorbing ghost KV writes into a live page
-        if self.cache_mode == "paged":
-            for i in range(self.slots):
-                if self.active[i] is None:
-                    assert (self.kv.tables[i] == TRASH_PAGE).all(), (
-                        f"dead slot {i} still maps pages "
-                        f"{self.kv.tables[i].tolist()} — its ghost decode "
-                        f"write would corrupt live KV")
-        self.last_decode_positions = positions.copy()
-        if self.cache_mode == "paged":
-            # active slots OUTSIDE the live list ride the decode batch as
-            # ghosts (positions pinned 0, no token sampled): mid-prefill
-            # slots and slots a speculative pass already served this step.
-            # Unlike dead slots their table rows map REAL pages (completed
-            # chunks / committed KV, possibly aliased), so the ghost write
-            # at position 0 must be redirected to trash in the decode
-            # call's table copy
-            ghosted = [i for i in range(self.slots)
-                       if self.active[i] is not None and i not in live]
-            if ghosted:
-                masked = self.kv.tables.copy()
-                masked[ghosted] = TRASH_PAGE
-                table = jnp.asarray(masked)
+        paged = self.cache_mode == "paged"
+        with self._span("engine/decode_inputs"):
+            if paged:
+                live = self._ensure_decode_pages(live, step)
+                if not live:
+                    return None, live
+            toks = np.zeros((self.slots, 1), np.int32)
+            ids = np.zeros((self.slots,), np.int32)
+            positions = np.zeros((self.slots,), np.int32)
+            for i in live:
+                toks[i, 0] = self.active[i].generated[-1]
+                ids[i] = self._slot_col(self.active[i])
+                positions[i] = self.positions[i]
+            # dead rows decode as ghosts (token 0, adapter 0): their
+            # positions are pinned to 0 above, and in paged mode their table
+            # rows must be all-trash — so a future table bug corrupts loudly
+            # here instead of silently absorbing ghost KV writes into a live
+            # page
+            if paged:
+                for i in range(self.slots):
+                    if self.active[i] is None:
+                        assert (self.kv.tables[i] == TRASH_PAGE).all(), (
+                            f"dead slot {i} still maps pages "
+                            f"{self.kv.tables[i].tolist()} — its ghost "
+                            f"decode write would corrupt live KV")
+            self.last_decode_positions = positions.copy()
+            if paged:
+                # active slots OUTSIDE the live list ride the decode batch
+                # as ghosts (positions pinned 0, no token sampled):
+                # mid-prefill slots and slots a speculative pass already
+                # served this step.  Unlike dead slots their table rows map
+                # REAL pages (completed chunks / committed KV, possibly
+                # aliased), so the ghost write at position 0 must be
+                # redirected to trash in the decode call's table copy
+                ghosted = [i for i in range(self.slots)
+                           if self.active[i] is not None and i not in live]
+                if ghosted:
+                    masked = self.kv.tables.copy()
+                    masked[ghosted] = TRASH_PAGE
+                    table = jnp.asarray(masked)
+                else:
+                    table = self.kv.table_jax()
+                cache = {"k": self.kv.pools["k"], "v": self.kv.pools["v"],
+                         "page_table": table}
             else:
-                table = self.kv.table_jax()
-            cache = {"k": self.kv.pools["k"], "v": self.kv.pools["v"],
-                     "page_table": table}
-            logits, new_cache = self._decode(
-                tree, {"tokens": jnp.asarray(toks)}, cache,
-                jnp.asarray(positions), jnp.asarray(ids))
+                cache = self.cache
+            batch = {"tokens": jnp.asarray(toks)}
+            positions, ids = jnp.asarray(positions), jnp.asarray(ids)
+        with self._span("engine/decode_dispatch"):
+            logits, new_cache = self._decode(tree, batch, cache, positions,
+                                             ids)
+            # stay on device: the fused sampler consumes this slice and
+            # only token ids (not (slots, vocab) logits) cross back to the
+            # host
+            rows = logits[:, -1, :self.cfg.vocab_size]
+        if paged:
             self.kv.pools = {"k": new_cache["k"], "v": new_cache["v"]}
         else:
-            logits, self.cache = self._decode(
-                tree, {"tokens": jnp.asarray(toks)}, self.cache,
-                jnp.asarray(positions), jnp.asarray(ids))
-        # stay on device: the fused sampler consumes this slice and only
-        # token ids (not (slots, vocab) logits) cross back to the host
-        return logits[:, -1, :self.cfg.vocab_size], live
+            self.cache = new_cache
+        return rows, live
 
     def _spec_step(self, tree, spec_live: List[int], step: int
                    ) -> Tuple[Dict[int, int], List[int]]:
@@ -1505,7 +1570,8 @@ class ServeEngine:
             jnp.asarray(positions), jnp.asarray(ids),
             temps, ks, ps, seeds, counters, k=k)
         kv.pools = new_pools
-        drafted = jax.device_get(drafted)      # (slots, k) — one D2H pull
+        with self._span("engine/device_wait"):
+            drafted = jax.device_get(drafted)  # (slots, k) — one D2H pull
         self._step_spent += cm.draft_cost(k)
         # --- verify: one g-row, (k+1)-wide paged prefill -----------------
         toks = np.zeros((g, w), np.int32)
@@ -1541,8 +1607,9 @@ class ServeEngine:
         target, chosen, top_ids, top_lps = self._sample_fn(
             flat, temps, ks, ps, seeds, counters, want_logprobs=want_lp)
         # batch the accept-path materialization the same way: one transfer
-        target, chosen, top_ids, top_lps = jax.device_get(
-            (target, chosen, top_ids, top_lps))
+        with self._span("engine/device_wait"):
+            target, chosen, top_ids, top_lps = jax.device_get(
+                (target, chosen, top_ids, top_lps))
         target = target.reshape((g, w))
         if want_lp:
             chosen = chosen.reshape((g, w))
@@ -1636,6 +1703,10 @@ class ServeEngine:
         admits = [b.admit_step for b in parent.branches
                   if b.admit_step is not None]
         parent.admit_step = min(admits) if admits else None
+        for stamp in ("t_admit", "t_first_token"):
+            ts = [getattr(b, stamp) for b in parent.branches
+                  if getattr(b, stamp) is not None]
+            setattr(parent, stamp, min(ts) if ts else None)
         steps = [b.finish_step for b in parent.branches
                  if b.finish_step is not None]
         parent.finish_step = max(steps) if steps else None
@@ -1748,6 +1819,8 @@ class ServeEngine:
         request.finish_step = None
         request.finish_cost = None
         request.preemptions = 0
+        request.t_submit = time.perf_counter()
+        request.t_admit = request.t_first_token = None
         request._prefill_done = True
         request._prefill_pos = 0
         # epoch pins are per-admission: a re-submitted request re-pins to
@@ -1793,6 +1866,7 @@ class ServeEngine:
         request.finish_step = None
         request.finish_cost = None
         request.preemptions = 0
+        request.t_admit = request.t_first_token = None
         request.branches = []
         self._inflight.add(request.uid)
         for b in range(request.n):
@@ -1811,6 +1885,7 @@ class ServeEngine:
             self.submit(br, arrival_step=arrival_step, _validated=True)
         request.arrival_step = request.branches[0].arrival_step
         request.arrival_cost = request.branches[0].arrival_cost
+        request.t_submit = request.branches[0].t_submit
 
     # -- serving -----------------------------------------------------------
     def run(self, requests: List[Request], max_steps: int = 512,
@@ -1840,6 +1915,74 @@ class ServeEngine:
             self.submit(r, arrival_step=0, _validated=True)
         return self.run_stream(max_steps=max_steps, lookahead=0,
                                preempt=False)
+
+    def _decode_step(self, tree, live: List[int], step: int,
+                     finished: List[Request]) -> int:
+        """Decode every live slot by one step (speculatively where asked),
+        append the tokens, and finish the slots that are done; returns how
+        many slots emitted tokens."""
+        cm = self.cost_model
+        #: slot -> tokens emitted this step (1 for plain decode,
+        #: accepted-length for speculative slots)
+        served: Dict[int, int] = {}
+        # the decode hot path makes ZERO tracker calls under the default
+        # NoopTracker (gated spans + gated _observe_decode): its only
+        # instrumentation cost is bool checks, pinned <2% by the overhead
+        # guard in benchmarks/bench_serve.py
+        with self._span("engine/decode_step_s"):
+            spec_live = [i for i in live
+                         if self._spec_for(self.active[i]) is not None]
+            if spec_live:
+                handled, demoted = self._spec_step(tree, spec_live, step)
+                served.update(handled)
+                # demoted spec slots (window clamped below 1) decode
+                # plainly this step; _spec_step may have suspended slots
+                # under pool pressure — drop those
+                plain = sorted(
+                    [i for i in live if i not in spec_live
+                     and self.active[i] is not None] + demoted)
+            else:
+                plain = live
+            if plain:
+                rows, plain = self._decode_live(tree, plain, step)
+            if plain:
+                # mid-prefill and spec-served slots ride the batch as
+                # ghosts: None rows draw no RNG and return no token
+                # (counter-based sampling stays aligned with the one-shot
+                # engine)
+                reqs: List[Optional[Request]] = [None] * self.slots
+                for i in plain:
+                    reqs[i] = self.active[i]
+                with self._span("engine/sample"):
+                    toks = self._sample_rows(rows, reqs,
+                                             draft_rows=len(served))
+        if plain:
+            self._step_spent += cm.decode_step_cost
+            for i in plain:
+                r = self.active[i]
+                r.generated.append(int(toks[i]))
+                self.positions[i] += 1
+                served[i] = 1
+        if self._obs and served:
+            self._observe_decode(sorted(served), served)
+        with self._span("engine/finish"):
+            for i in sorted(served):
+                r = self.active[i]
+                if r is None:
+                    continue
+                if self._hit_stop(r):
+                    # stop id emitted (possibly mid-verify-window): finish
+                    # NOW — pages free this step and the slot refills at
+                    # the next admission pass
+                    self._finish_slot(i, finished, step, reason="stop")
+                elif (len(r.generated) >= r.max_new_tokens
+                        or self.positions[i] >= self.max_len - 1):
+                    self._finish_slot(i, finished, step)
+        if self._obs and cm.step_budget is not None:
+            self._tracker.gauge("engine/step_budget_utilization",
+                                self._step_spent / cm.step_budget,
+                                step=self._obs_step)
+        return len(served)
 
     def run_stream(self,
                    arrivals: Optional[Iterable[Tuple[int, Request]]] = None,
@@ -1912,96 +2055,39 @@ class ServeEngine:
             # mutations then apply in one refresh — never mid-step
             for hook in tuple(self._step_hooks):
                 hook(self, steps)
-            tree = self._refresh_tree(tree)
-            # mid-prefill slots advance a chunk before new admissions
-            # compete for the step's budget
-            self._continue_prefills(tree, steps)
-            self._admit(steps)
-            # a prefill-sampled first token may already be a stop id (or
-            # the whole budget): finish + refill before decoding
-            self._finish_admitted(finished, steps)
-            busy = [i for i, r in enumerate(self.active) if r is not None]
-            live = [i for i in busy
-                    if getattr(self.active[i], "_prefill_done", True)]
-            max_live = max(max_live, len(busy))
-            if not live:
-                if (not busy and self.cache_mode == "paged"
-                        and self.scheduler.has_work()
-                        and next_arrival >= len(trace)):
-                    head = self.scheduler.window(self._cost_clock)[0][0]
-                    raise self.kv.oom(
-                        f"request {head.uid} (prompt {len(head.prompt)} "
-                        f"tokens) cannot fit an idle page pool of "
-                        f"{self.kv.num_pages - 1} pages x "
-                        f"{self.kv.page_size} "
-                        f"({self.kv.pages_resident()} resident, "
-                        f"{self.kv.pages_resident() - self.kv.pages_in_use()}"
-                        f" retained)")
-                self.last_run_step_costs.append((self._step_spent, 0))
-                continue
-            # the decode hot path makes ZERO tracker calls under the
-            # default NoopTracker (gated span + gated _observe_decode):
-            # its only instrumentation cost is these bool checks, pinned
-            # <2% by the overhead guard in benchmarks/bench_serve.py
-            span = (self._tracker.time_block("engine/decode_step_s",
-                                             step=self._obs_step)
-                    if self._obs else NULL_SPAN)
-            #: slot -> tokens emitted this step (1 for plain decode,
-            #: accepted-length for speculative slots)
-            served: Dict[int, int] = {}
-            with span:
-                spec_live = [i for i in live
-                             if self._spec_for(self.active[i]) is not None]
-                if spec_live:
-                    handled, demoted = self._spec_step(tree, spec_live,
-                                                       steps)
-                    served.update(handled)
-                    # demoted spec slots (window clamped below 1) decode
-                    # plainly this step; _spec_step may have suspended
-                    # slots under pool pressure — drop those
-                    plain = sorted(
-                        [i for i in live if i not in spec_live
-                         and self.active[i] is not None] + demoted)
+            with self._span("engine/step"):
+                tree = self._refresh_tree(tree)
+                # mid-prefill slots advance a chunk before new admissions
+                # compete for the step's budget
+                with self._span("engine/continue_prefills"):
+                    self._continue_prefills(tree, steps)
+                self._admit(steps)
+                # a prefill-sampled first token may already be a stop id (or
+                # the whole budget): finish + refill before decoding
+                with self._span("engine/finish"):
+                    self._finish_admitted(finished, steps)
+                busy = [i for i, r in enumerate(self.active) if r is not None]
+                live = [i for i in busy
+                        if getattr(self.active[i], "_prefill_done", True)]
+                max_live = max(max_live, len(busy))
+                if live:
+                    served = self._decode_step(tree, live, steps, finished)
                 else:
-                    plain = live
-                if plain:
-                    rows, plain = self._decode_live(tree, plain, steps)
-                if plain:
-                    # mid-prefill and spec-served slots ride the batch as
-                    # ghosts: None rows draw no RNG and return no token
-                    # (counter-based sampling stays aligned with the
-                    # one-shot engine)
-                    reqs: List[Optional[Request]] = [None] * self.slots
-                    for i in plain:
-                        reqs[i] = self.active[i]
-                    toks = self._sample_rows(rows, reqs,
-                                             draft_rows=len(served))
-            if plain:
-                self._step_spent += cm.decode_step_cost
-                for i in plain:
-                    r = self.active[i]
-                    r.generated.append(int(toks[i]))
-                    self.positions[i] += 1
-                    served[i] = 1
-            if self._obs and served:
-                self._observe_decode(sorted(served), served)
-            for i in sorted(served):
-                r = self.active[i]
-                if r is None:
-                    continue
-                if self._hit_stop(r):
-                    # stop id emitted (possibly mid-verify-window): finish
-                    # NOW — pages free this step and the slot refills at
-                    # the next admission pass
-                    self._finish_slot(i, finished, steps, reason="stop")
-                elif (len(r.generated) >= r.max_new_tokens
-                        or self.positions[i] >= self.max_len - 1):
-                    self._finish_slot(i, finished, steps)
-            if self._obs and cm.step_budget is not None:
-                self._tracker.gauge("engine/step_budget_utilization",
-                                    self._step_spent / cm.step_budget,
-                                    step=self._obs_step)
-            self.last_run_step_costs.append((self._step_spent, len(served)))
+                    if (not busy and self.cache_mode == "paged"
+                            and self.scheduler.has_work()
+                            and next_arrival >= len(trace)):
+                        head = self.scheduler.window(self._cost_clock)[0][0]
+                        kv = self.kv
+                        retained = kv.pages_resident() - kv.pages_in_use()
+                        raise kv.oom(
+                            f"request {head.uid} (prompt {len(head.prompt)} "
+                            f"tokens) cannot fit an idle page pool of "
+                            f"{kv.num_pages - 1} pages x {kv.page_size} "
+                            f"({kv.pages_resident()} resident, {retained} "
+                            f"retained)")
+                    served = 0
+                self.last_run_step_costs.append((self._step_spent, served))
+            self._observe_compiles(steps)
         #: engine iterations the last run took — the deterministic
         #: wave-serialization metric (a wave engine pays ~one full
         #: prefill+decode pass per adapter switch; per-slot batching doesn't)

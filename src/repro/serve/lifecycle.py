@@ -272,7 +272,6 @@ class AdapterLifecycle:
         s = self.engine._obs_step
         tr.gauge("engine/bank/epoch", self.current.version, step=s)
         tr.gauge("engine/bank/columns", len(self.columns), step=s)
-        tr.gauge("engine/bank/live_epochs", len(self.live), step=s)
 
     def _sync_engine_views(self) -> None:
         # keep the engine's historical views coherent: _adapter_index IS

@@ -1,9 +1,12 @@
 """Observability subsystem (repro.obs): tracker primitive semantics, jsonl
 schema round-trip, and end-to-end capture of an instrumented streaming
 serve run — the captured aggregates must agree with the ``Request`` stamps
-and KV-cache stats the engine keeps independently, and instrumentation must
-add no recompiles and change no outputs."""
+and KV-cache stats the engine keeps independently, the engine's step spans
+must nest, and instrumentation must add no recompiles and change no
+outputs."""
+import glob
 import json
+import time
 
 import jax
 import numpy as np
@@ -11,10 +14,11 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import model as model_lib
-from repro.obs import (NOOP, SCHEMA_VERSION, CompositeTracker,
+from repro.obs import (NOOP, NULL_SPAN, SCHEMA_VERSION, CompositeTracker,
                        InMemoryTracker, JsonlTracker, NoopTracker, Tracker,
-                       read_jsonl, replay)
+                       clock_ns, read_jsonl, replay)
 from repro.serve import PagedKVCache, Request, ServeEngine
+from repro.serve import engine as engine_lib
 from repro.serve import sampling as sampling_lib
 from repro.train import trainer
 
@@ -79,13 +83,79 @@ def test_noop_tracker_discards_and_shares_null_span():
     t = NoopTracker()
     assert t.is_noop and NOOP.is_noop and not InMemoryTracker().is_noop
     # spans are one shared object: no allocation, no clock read per use
-    assert t.time_block("a") is t.time_block("b")
+    assert t.time_block("a") is t.time_block("b") is NULL_SPAN
     with t.time_block("c"):
         pass
     t.count("x", -5)  # noop doesn't even validate — pure discard
     t.gauge("x", 1)
     t.log({"a": 1})
     t.event("e", {})
+    t.span("s", 5, 1)  # nor does a span's interval get checked
+
+
+def test_span_record_parent_and_uid():
+    t = InMemoryTracker()
+    with t.time_block("outer", step=2):
+        with t.time_block("inner"):
+            pass
+        t.span("request/queued", 10, 20, uid=7)
+    with t.time_block("second"):
+        pass
+    outer, inner, req, second = (t.spans_named(n)[0] for n in (
+        "outer", "inner", "request/queued", "second"))
+    assert outer["parent"] is None and second["parent"] is None
+    assert inner["parent"] == "outer"
+    assert req["parent"] is None and req["uid"] == 7
+    assert (req["start_ns"], req["end_ns"]) == (10, 20)
+    assert inner["uid"] is None and outer["step"] == 2
+    assert outer["start_ns"] <= inner["start_ns"] <= inner["end_ns"] \
+        <= outer["end_ns"] <= second["start_ns"]
+    # records arrive as spans end: the inner one first
+    assert [s["name"] for s in t.spans] == ["inner", "request/queued",
+                                           "outer", "second"]
+    with pytest.raises(ValueError, match="ends before"):
+        t.span("bad", 2, 1)
+
+
+def test_span_duration_is_histogram_value():
+    t = InMemoryTracker()
+    with t.time_block("s") as sp:
+        time.sleep(0.002)
+    rec = t.spans_named("s")[0]
+    assert (rec["start_ns"], rec["end_ns"]) == (sp.start_ns, sp.end_ns)
+    assert t.values("s") == [sp.seconds] == \
+        [(rec["end_ns"] - rec["start_ns"]) / 1e9]
+    assert sp.seconds >= 0.002
+    # one record per span: no separate histogram record
+    assert len(t.spans) == 1 and len(t.histograms) == 1
+    # the span clock is time.time_ns; perf_counter stamps map onto it
+    assert abs(clock_ns(time.perf_counter()) - time.time_ns()) < 1e6
+
+
+def test_time_block_marks_profiler_host_plane(tmp_path):
+    """A span's interval appears in a profiler trace's host plane, on the
+    trace's clock: one constant offset from the tracker's record."""
+    t = InMemoryTracker()
+    jax.profiler.start_trace(str(tmp_path))
+    for i in range(3):
+        with t.time_block(f"probe/{i}"):
+            time.sleep(0.001 * (i + 1))
+    jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                         "*.xplane.pb"))[0]
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("probe/"):
+                    found[ev.name] = (int(ev.start_ns), int(ev.duration_ns))
+    assert sorted(found) == ["probe/0", "probe/1", "probe/2"]
+    offsets = []
+    for name, (start, dur) in found.items():
+        rec = t.spans_named(name)[0]
+        assert abs(dur - (rec["end_ns"] - rec["start_ns"])) < 100_000
+        offsets.append(rec["start_ns"] - start)
+    assert max(offsets) - min(offsets) < 100_000
 
 
 def test_composite_fans_out():
@@ -134,6 +204,34 @@ def test_jsonl_round_trip(tmp_path):
     assert mem.values("engine/decode_step_s") == [0.01]
     assert mem.scalars["train/loss"] == [(2, 2.25)]
     assert mem.events_named("engine/admission")[0]["uid"] == 0
+
+
+def test_jsonl_span_round_trip_and_replay(tmp_path):
+    path = str(tmp_path / "spans.jsonl")
+    with JsonlTracker(path) as t:
+        with t.time_block("engine/step", step=3):
+            with t.time_block("engine/sample"):
+                pass
+        t.span("request/queued", 100, 250, uid=4)
+    recs = read_jsonl(path)
+    assert [r["kind"] for r in recs] == ["span"] * 3
+    step, sample = recs[1], recs[0]
+    assert sample["data"]["parent"] == "engine/step"
+    assert step["value"] == (step["data"]["end_ns"]
+                             - step["data"]["start_ns"]) / 1e9
+    mem = replay(recs)
+    assert [(s["name"], s["parent"], s["uid"], s["start_ns"], s["end_ns"])
+            for s in mem.spans] == \
+        [(r["name"], r["data"]["parent"], r["data"]["uid"],
+          r["data"]["start_ns"], r["data"]["end_ns"]) for r in recs]
+    assert mem.values("request/queued") == [150e-9]
+    assert mem.values("engine/step") == [step["value"]]
+    bad = dict(recs[2], data={"start_ns": 5, "end_ns": 1, "parent": None,
+                              "uid": None})
+    p = tmp_path / "bad.jsonl"
+    p.write_text(json.dumps(bad) + "\n")
+    with pytest.raises(ValueError, match="span record"):
+        read_jsonl(str(p))
 
 
 def test_jsonl_rejects_malformed(tmp_path):
@@ -267,17 +365,165 @@ def test_stream_capture_matches_request_stamps(setup):
 
 def test_instrumentation_no_recompiles_no_output_change(setup):
     """Swapping a recording tracker onto a warmed engine must trigger zero
-    new sampler traces and leave greedy outputs bit-identical."""
+    new sampler, decode or prefill traces and leave greedy outputs
+    bit-identical."""
     cfg, params = setup
     eng = _stream_engine(params, cfg)          # default NoopTracker
     base = eng.run_stream(_pressure_workload(cfg), max_steps=200)
-    before = sampling_lib.trace_count()
-    eng.tracker = InMemoryTracker()
+    # a second run aliases the first run's retained pages: its prefills
+    # are warmed here too, before the counts are taken
+    eng.run_stream(_pressure_workload(cfg), max_steps=200)
+    before = (sampling_lib.trace_count(), eng.decode_trace_count(),
+              eng.prefill_trace_count())
+    tr = InMemoryTracker()
+    eng.tracker = tr
     instrumented = eng.run_stream(_pressure_workload(cfg), max_steps=200)
-    assert sampling_lib.trace_count() == before, \
-        "attaching a tracker recompiled the sampler"
+    assert (sampling_lib.trace_count(), eng.decode_trace_count(),
+            eng.prefill_trace_count()) == before, \
+        "attaching a tracker recompiled a program"
+    assert tr.counter("engine/compiles") == 0
+    assert len(tr.spans_named("engine/step")) > 0
     assert {r.uid: r.generated for r in base} == \
         {r.uid: r.generated for r in instrumented}
+
+
+#: every span the engine's step opens (plain decode, chunked prefill)
+STEP_SPANS = ("engine/step", "engine/continue_prefills", "engine/admit",
+              "kv/admit_pages", "engine/decode_inputs",
+              "engine/decode_dispatch", "engine/sample",
+              "engine/device_wait", "engine/finish", "engine/prefill_s",
+              "engine/decode_step_s")
+
+
+def _chunked_engine(params, cfg, tracker=None):
+    return ServeEngine(params, cfg, max_len=64, slots=2, cache_mode="paged",
+                       page_size=8, prefill_chunk_tokens=8,
+                       bucket_multiple=8, tracker=tracker)
+
+
+def _chunked_workload(cfg):
+    """Three requests through two slots: queueing, a prompt of four
+    chunks, and a prompt that fits one chunk."""
+    return [(0, Request(uid=i, prompt=(np.arange(n, dtype=np.int32) * 5
+                                       + i) % cfg.vocab_size,
+                        max_new_tokens=m))
+            for i, (n, m) in enumerate([(30, 4), (6, 3), (12, 5)])]
+
+
+def test_engine_step_spans_nest(setup):
+    """Every step span appears, each child lies inside a span of its
+    parent's name, request spans carry their uid, and the three request
+    stamps are ordered."""
+    cfg, params = setup
+    tr = InMemoryTracker()
+    eng = _chunked_engine(params, cfg, tracker=tr)
+    done = eng.run_stream(_chunked_workload(cfg), max_steps=100)
+    assert len(done) == 3 and all(r.done for r in done)
+    names = {s["name"] for s in tr.spans}
+    assert set(STEP_SPANS) <= names
+    assert {"request/queued", "request/prefill"} <= names
+    for sp in tr.spans:
+        if sp["name"].startswith("request/"):
+            continue
+        if sp["name"] == "engine/step":
+            assert sp["parent"] is None
+            continue
+        assert sp["parent"] is not None, sp
+        assert any(p["start_ns"] <= sp["start_ns"]
+                   and sp["end_ns"] <= p["end_ns"]
+                   for p in tr.spans_named(sp["parent"])), sp
+    # one engine/step per engine step, and the device waits of a decode
+    # nest in its sample span
+    assert len(tr.spans_named("engine/step")) == eng.last_run_steps
+    waits = {s["parent"] for s in tr.spans_named("engine/device_wait")}
+    assert waits == {"engine/sample"}
+    for r in done:
+        assert r.t_submit <= r.t_admit <= r.t_first_token
+        (queued,) = [s for s in tr.spans_named("request/queued")
+                     if s["uid"] == r.uid]
+        (prefill,) = [s for s in tr.spans_named("request/prefill")
+                      if s["uid"] == r.uid]
+        assert queued["end_ns"] == pytest.approx(prefill["start_ns"],
+                                                 abs=1e5)
+        assert (prefill["end_ns"] - prefill["start_ns"]) / 1e9 == \
+            pytest.approx(r.t_first_token - r.t_admit, abs=1e-5)
+
+
+def test_engine_compiles_counted_per_step(setup):
+    """``engine/compiles`` stays 0 on a warmed engine and counts, with an
+    ``engine/compile`` event naming the program, a new prefill shape."""
+    cfg, params = setup
+    eng = _stream_engine(params, cfg)
+    for _ in range(2):
+        eng.run_stream(_pressure_workload(cfg), max_steps=200)
+    tr = InMemoryTracker()
+    eng.tracker = tr
+    eng.run_stream(_pressure_workload(cfg), max_steps=200)
+    assert tr.counter("engine/compiles") == 0
+    assert tr.events_named("engine/compile") == []
+    before = eng.prefill_trace_count()
+    # a prompt in a padding bucket the workload never reached
+    long = Request(uid=10, prompt=np.arange(40, dtype=np.int32),
+                   max_new_tokens=2)
+    eng.run_stream([(0, long)], max_steps=20)
+    new = eng.prefill_trace_count() - before
+    assert new >= 1
+    assert tr.counter("engine/compiles") == new
+    events = tr.events_named("engine/compile")
+    assert len(events) == new and events[0]["program"] == "prefill"
+    assert events[0]["step"] == 1
+
+
+class _CountingNoop(NoopTracker):
+    """A no-op tracker that counts every call made on it."""
+
+    def __init__(self):
+        super().__init__()
+        self.calls = 0
+
+    def _hit(self, *a, **k):
+        self.calls += 1
+
+    count = gauge = histogram = log = event = span = _hit
+
+    def time_block(self, *a, **k):
+        self.calls += 1
+        return NULL_SPAN
+
+
+class _CountingClock:
+    """Stands in for the engine module's ``time``: counts clock reads."""
+
+    def __init__(self):
+        self.reads = {"perf_counter": 0, "time_ns": 0}
+
+    def perf_counter(self):
+        self.reads["perf_counter"] += 1
+        return time.perf_counter()
+
+    def time_ns(self):
+        self.reads["time_ns"] += 1
+        return time.time_ns()
+
+
+def test_noop_engine_makes_no_tracker_calls_or_clock_reads(setup,
+                                                           monkeypatch):
+    """Under a no-op tracker the engine's steps call the tracker zero times
+    and read the clock only for the three stamps of each request."""
+    cfg, params = setup
+    tr = _CountingNoop()
+    eng = _chunked_engine(params, cfg, tracker=tr)
+    eng.run_stream(_chunked_workload(cfg), max_steps=100)   # compiles
+    clock = _CountingClock()
+    monkeypatch.setattr(engine_lib, "time", clock)
+    steps = []
+    eng.add_step_hook(lambda e, step: steps.append(tr.calls))
+    done = eng.run_stream(_chunked_workload(cfg), max_steps=100)
+    assert all(r.done for r in done) and len(steps) > 3
+    assert tr.calls == 0 and set(steps) == {0}
+    assert clock.reads == {"perf_counter": 3 * len(done), "time_ns": 0}
+    for r in done:
+        assert r.t_submit <= r.t_admit <= r.t_first_token
 
 
 def test_engine_reuse_across_runs_keeps_steps_monotone(setup):
