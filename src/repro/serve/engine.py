@@ -80,6 +80,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, PEFTConfig
 from repro.core import peft as peft_lib, registry as peft_registry
+from repro.kernels.paged_decode_attention import pages_read
 from repro.models import model as model_lib
 from repro.obs import NOOP, NULL_SPAN, Tracker, clock_ns
 from repro.serve import sampling as sampling_lib
@@ -1450,6 +1451,14 @@ class ServeEngine:
                     table = self.kv.table_jax()
                 cache = {"k": self.kv.pools["k"], "v": self.kv.pools["v"],
                          "page_table": table}
+                if self._obs:
+                    s = self._obs_step
+                    self._tracker.count(
+                        "kv/decode_pages_read",
+                        pages_read(positions + 1, self.kv.page_size), step=s)
+                    self._tracker.count(
+                        "kv/decode_pages_table",
+                        self.slots * self.kv.pages_per_slot, step=s)
             else:
                 cache = self.cache
             batch = {"tokens": jnp.asarray(toks)}

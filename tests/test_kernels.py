@@ -136,13 +136,27 @@ def test_psoft_matmul_grads_match_reference():
                                    atol=1e-2, rtol=1e-3)
 
 
-@pytest.mark.parametrize("b,h,kh,hd,pages,pg,maxp", [
-    (4, 8, 4, 64, 16, 8, 4),      # GQA
-    (2, 4, 1, 128, 8, 16, 3),     # MQA
-    (3, 8, 8, 32, 12, 8, 2),      # MHA
-])
+_DECODE_CASES = [   # (b, h, kh, hd, pages, pg, maxp), lengths
+    ((4, 8, 4, 64, 16, 8, 4), None),      # GQA
+    ((2, 4, 1, 128, 8, 16, 3), None),     # MQA
+    ((3, 8, 8, 32, 12, 8, 2), None),      # MHA
+    # at KH 8, D 128, pg 16 a block is 16 pages, so maxp 20 leaves a last
+    # block of 4: a page boundary, a block boundary, a full row, length 1
+    ((4, 16, 8, 128, 48, 16, 20), (16, 256, 320, 1)),
+    ((3, 16, 8, 128, 48, 16, 20), (255, 257, 33)),    # either side of one
+    ((16, 16, 8, 128, 300, 16, 128), None),   # serving widths, 16 slots
+    # one KV head: a partial sublane tile per page, 16 pages a block again
+    ((3, 4, 1, 128, 48, 16, 20), (16, 256, 300)),
+]
+
+
+@pytest.mark.parametrize(
+    "b,h,kh,hd,pages,pg,maxp,lens", [c + (n,) for c, n in _DECODE_CASES],
+    ids=["-".join(map(str, c)) + ("-len" + "_".join(map(str, n)) if n
+                                  else "") for c, n in _DECODE_CASES])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_vs_ref(b, h, kh, hd, pages, pg, maxp, dtype):
+def test_paged_decode_attention_vs_ref(b, h, kh, hd, pages, pg, maxp, lens,
+                                       dtype):
     """Scalar-prefetched page-DMA decode kernel == gather-based oracle,
     including rows whose tail pages are fully masked."""
     keys = jax.random.split(jax.random.PRNGKey(b), 3)
@@ -154,7 +168,8 @@ def test_paged_decode_attention_vs_ref(b, h, kh, hd, pages, pg, maxp, dtype):
     page_table = jax.random.randint(jax.random.PRNGKey(7), (b, maxp),
                                     0, pages)
     rng = np.random.default_rng(b)
-    lengths = jnp.asarray(rng.integers(1, maxp * pg, size=b), jnp.int32)
+    lengths = jnp.asarray(lens or rng.integers(1, maxp * pg, size=b),
+                          jnp.int32)
     want = ref.paged_decode_attention_ref(
         q.astype(jnp.float32), k_pool.astype(jnp.float32),
         v_pool.astype(jnp.float32), page_table, lengths)
@@ -174,6 +189,59 @@ def test_paged_decode_attention_dead_rows():
         q, pool, pool, jnp.zeros((4, 3), jnp.int32), jnp.zeros((4,),
                                                                jnp.int32))
     assert np.all(np.asarray(out) == 0.0)
+
+
+@pytest.mark.parametrize("kh,hd,pg,maxp,dtype", [
+    (8, 128, 16, 20, jnp.bfloat16),    # two blocks of 16 pages, last of 4
+    (4, 64, 8, 6, jnp.float32),
+    (1, 128, 16, 20, jnp.bfloat16),    # one KV head, two blocks
+])
+def test_paged_decode_never_reads_dead_pages(kh, hd, pg, maxp, dtype):
+    """Every pool position past a row's length -- the tail of its last
+    page, its later pages, and the trash page but for the position a dead
+    row reads -- holds NaN.  The kernel must neither copy nor weigh them:
+    its output matches the oracle on a clean pool (0 * NaN would not)."""
+    lengths = np.array([1, pg + 1, 3 * pg, maxp * pg - 5, maxp * pg, 1])
+    b, h = len(lengths), 2 * kh
+    dead = 0                                       # page 0: the trash page
+    table = 1 + np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
+    table[lengths == 1] = dead                     # dead rows, as pinned
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    shape = (1 + b * maxp, pg, kh, hd)
+    q = jax.random.normal(keys[0], (b, h, hd)).astype(dtype)
+    k_pool = np.array(jax.random.normal(keys[1], shape), np.float32)
+    v_pool = np.array(jax.random.normal(keys[2], shape), np.float32)
+    want = ref.paged_decode_attention_ref(
+        q.astype(jnp.float32), jnp.asarray(k_pool, dtype).astype(jnp.float32),
+        jnp.asarray(v_pool, dtype).astype(jnp.float32), jnp.asarray(table),
+        jnp.asarray(lengths, jnp.int32))
+    live = np.zeros(shape[:2], bool)               # (page, position)
+    live[dead, 0] = True
+    for row, n in enumerate(lengths):
+        for t in range(n):
+            live[table[row, t // pg], t % pg] = True
+    for pool in (k_pool, v_pool):
+        pool[~live] = np.nan
+    got = ops.paged_decode_attention(
+        q, jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype),
+        jnp.asarray(table), jnp.asarray(lengths, jnp.int32))
+    got = np.asarray(got.astype(jnp.float32))
+    assert np.isfinite(got).all()
+    tol = 1e-5 if dtype == jnp.float32 else 5e-2
+    np.testing.assert_allclose(got, np.asarray(want), atol=tol, rtol=tol)
+
+
+def test_pages_per_block_follows_shapes():
+    """A block is the largest power of two of pages under the VMEM budget:
+    256 tokens at the serving widths, fewer for wide MHA pages, never more
+    than the table holds."""
+    from repro.kernels.paged_decode_attention import _pages_per_block
+    assert _pages_per_block(16, 8, 128, 2, 128) == 16
+    assert _pages_per_block(16, 8, 128, 2, 20) == 16
+    assert _pages_per_block(16, 8, 128, 2, 3) == 3
+    assert _pages_per_block(16, 32, 128, 2, 128) == 4
+    assert _pages_per_block(16, 32, 256, 2, 128) == 2
+    assert _pages_per_block(16, 1, 64, 2, 128) == 16
 
 
 def test_paged_decode_matches_dense_decode_attention():

@@ -556,6 +556,24 @@ def test_deprecated_log_shims(setup):
 
 # -- KV cache capture --------------------------------------------------------
 
+def test_decode_pages_read_and_table_counted(setup):
+    """The decode step counts the pages its attention kernel copies
+    (ceil(len / page_size) per row, a dead row at length 1) against the
+    page table's size (rows x pages_per_slot)."""
+    cfg, params = setup
+    tr = InMemoryTracker()
+    eng = ServeEngine(params, cfg, max_len=64, slots=2, cache_mode="paged",
+                      page_size=8, tracker=tr)
+    r = Request(uid=0, prompt=np.arange(14, dtype=np.int32),
+                max_new_tokens=6)
+    assert eng.run_stream([(0, r)], max_steps=32)[0].done
+    # prefill emits token 1; decodes at positions 14..18 emit tokens 2..6,
+    # so slot 0 reads at lengths 15..19 and the dead slot 1 at length 1
+    live = [-(-n // 8) for n in range(15, 20)]          # 2, 2, 3, 3, 3
+    assert tr.counter("kv/decode_pages_read") == sum(live) + len(live)
+    assert tr.counter("kv/decode_pages_table") == len(live) * 2 * 8
+
+
 def test_kv_prefix_hit_tokens_counted(setup):
     cfg, params = setup
     kv = PagedKVCache(cfg, slots=2, max_len=32, page_size=8)

@@ -19,6 +19,8 @@ from repro.kernels import ops
 
 D_MODEL, D_FF, HEADS, KV_HEADS, HEAD_DIM, RANK = 2048, 8192, 16, 8, 128, 64
 SLOTS, PAGE, PAGES, PAGES_PER_SLOT, CHUNK = 4, 16, 513, 128, 128
+#: the serving benchmark's decode batch: 16 slots of 128 pages each
+SERVE_SLOTS = 16
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +57,23 @@ def _kernel_cases():
                 q, kp, vp, pt, ln, interpret=False),
             [((SLOTS, HEADS, HEAD_DIM), bf), pool, pool,
              ((SLOTS, PAGES_PER_SLOT), i32), ((SLOTS,), i32)]),
+        "paged_decode_attention_serving": (
+            lambda q, kp, vp, pt, ln: ops.paged_decode_attention(
+                q, kp, vp, pt, ln, interpret=False),
+            [((SERVE_SLOTS, HEADS, HEAD_DIM), bf),
+             ((1 + SERVE_SLOTS * PAGES_PER_SLOT, PAGE, KV_HEADS, HEAD_DIM),
+              bf),
+             ((1 + SERVE_SLOTS * PAGES_PER_SLOT, PAGE, KV_HEADS, HEAD_DIM),
+              bf),
+             ((SERVE_SLOTS, PAGES_PER_SLOT), i32), ((SERVE_SLOTS,), i32)]),
+        # heads narrower than one 128-lane tile, as lm-100m serves them
+        # (12 heads, 4 KV heads of 64)
+        "paged_decode_attention_head_dim_64": (
+            lambda q, kp, vp, pt, ln: ops.paged_decode_attention(
+                q, kp, vp, pt, ln, interpret=False),
+            [((SLOTS, 12, 64), bf), ((PAGES, PAGE, 4, 64), bf),
+             ((PAGES, PAGE, 4, 64), bf), ((SLOTS, PAGES_PER_SLOT), i32),
+             ((SLOTS,), i32)]),
         "paged_prefill_attention": (
             lambda q, k, v, kp, vp, pt, pl: ops.paged_prefill_attention(
                 q, k, v, kp, vp, pt, pl, interpret=False),
